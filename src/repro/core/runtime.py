@@ -1,6 +1,7 @@
 """Live multi-task JAX runtime: MSched driving *real* array migrations.
 
-Each task is a real (reduced-config) model from the zoo whose parameters are
+Each task is a real model from the zoo (at its published widths, or its
+``.reduced()`` cut for CPU tests) whose parameters are
 page-granular segments in a task address space. "HBM" is a budgeted device
 pool: resident segments are ``jax.Array``s, evicted segments live as host
 numpy copies. On every context switch the MSched coordinator predicts the
@@ -16,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,13 +46,21 @@ class Segment:
 
 
 class LiveModelTask:
-    """A decode job over a reduced model; weights are pageable segments."""
+    """A decode job over one model; weights are pageable segments."""
 
-    def __init__(self, task_id: int, arch: str, page_size: int = 4096, seed: int = 0):
+    def __init__(
+        self,
+        task_id: int,
+        arch: str,
+        page_size: int = 4096,
+        seed: int = 0,
+        reduced: bool = True,
+    ):
         from repro.models.model import build_model
 
         self.task_id = task_id
-        self.cfg = get_config(arch).reduced()
+        cfg = get_config(arch)
+        self.cfg = cfg.reduced() if reduced else cfg
         self.fns = build_model(self.cfg)
         self.space = AddressSpace(page_size=page_size, base=(task_id + 1) << 44)
         params = self.fns.init(jax.random.PRNGKey(seed))
@@ -108,11 +117,25 @@ class LiveModelTask:
 
 @dataclasses.dataclass
 class LiveStats:
+    """Counters per task id; the totals over tasks are properties."""
+
     steps: Dict[int, int]
-    migrated_in_bytes: int
-    migrated_out_bytes: int
-    demand_faults: int
+    in_bytes: Dict[int, int]  # host -> device
+    out_bytes: Dict[int, int]  # device -> host (evictions)
+    faults: Dict[int, int]  # demand faults
     switch_wall_s: List[float]
+
+    @property
+    def migrated_in_bytes(self) -> int:
+        return sum(self.in_bytes.values())
+
+    @property
+    def migrated_out_bytes(self) -> int:
+        return sum(self.out_bytes.values())
+
+    @property
+    def demand_faults(self) -> int:
+        return sum(self.faults.values())
 
 
 class LiveRuntime:
@@ -123,9 +146,10 @@ class LiveRuntime:
         tasks: List[LiveModelTask],
         hbm_budget_bytes: int,
         steps_per_slice: int = 4,
-        page_size: int = 4096,
     ):
         self.tasks = {t.task_id: t for t in tasks}
+        # the pool pages at the tasks' extent, so they must share one
+        (page_size,) = {t.space.page_size for t in tasks}
         self.page_size = page_size
         self.pool = HBMPool(max(1, hbm_budget_bytes // page_size))
         # offline phase: profile + analyze (real MSched flow)
@@ -137,32 +161,41 @@ class LiveRuntime:
             h = TaskHelper(t.task_id, t.space, TemplatePredictor(descriptors))
             self.helpers[t.task_id] = h
             self.coordinator.register(h)
-        # page -> (task, segment) index for real data movement
-        self.page_owner: Dict[int, Tuple[int, int]] = {}
-        for t in tasks:
-            for si, seg in enumerate(t.segments):
-                for p in t.space.pages_of_extent((seg.base, seg.nbytes)):
-                    self.page_owner[p] = (t.task_id, si)
         self.steps_per_slice = steps_per_slice
         self.policy = RoundRobinPolicy(quantum_us=1000.0 * steps_per_slice)
-        self.stats = LiveStats({t.task_id: 0 for t in tasks}, 0, 0, 0, [])
-        self._step_counter = {t.task_id: 0 for t in tasks}
+        ids = list(self.tasks)
+        self.stats = LiveStats(
+            dict.fromkeys(ids, 0),
+            dict.fromkeys(ids, 0),
+            dict.fromkeys(ids, 0),
+            dict.fromkeys(ids, 0),
+            [],
+        )
+        self._step_counter = dict.fromkeys(ids, 0)
+        # logits of every step the last run() served, per task, in step order
+        self.outputs: Dict[int, List[np.ndarray]] = {}
 
     # -- real data movement ---------------------------------------------------
     def _sync_residency(self) -> None:
         """Make device arrays mirror the pool's residency decisions: a
-        segment is on-device iff all of its pages are pool-resident."""
+        segment is on-device iff all of its pages are pool-resident. Every
+        eviction runs before any host->device copy, so the device never holds
+        the outgoing and the incoming working sets at once."""
+        fetch = []
         for task in self.tasks.values():
+            tid = task.task_id
             for seg in task.segments:
                 pages = task.space.pages_of_extent((seg.base, seg.nbytes))
                 resident = all(self.pool.resident(p) for p in pages)
                 if resident and seg.device is None:
-                    seg.device = jax.device_put(jnp.asarray(seg.host))  # H2D
-                    self.stats.migrated_in_bytes += seg.nbytes
+                    fetch.append((tid, seg))
                 elif not resident and seg.device is not None:
                     seg.host = np.asarray(seg.device)  # D2H eviction
                     seg.device = None
-                    self.stats.migrated_out_bytes += seg.nbytes
+                    self.stats.out_bytes[tid] += seg.nbytes
+        for tid, seg in fetch:
+            seg.device = jax.device_put(seg.host)  # H2D
+            self.stats.in_bytes[tid] += seg.nbytes
 
     def _fault_in(self, task: LiveModelTask) -> None:
         """Demand-paging fallback: any still-missing segment faults in."""
@@ -171,10 +204,11 @@ class LiveRuntime:
                 pages = list(task.space.pages_of_extent((seg.base, seg.nbytes)))
                 self.pool.migrate(pages)
                 self._sync_residency()
-                self.stats.demand_faults += 1
+                self.stats.faults[task.task_id] += 1
 
     # -- main loop -------------------------------------------------------------
     def run(self, total_slices: int = 12) -> LiveStats:
+        self.outputs = {tid: [] for tid in self.tasks}
         for _ in range(total_slices):
             sched = {tid: SchedTask(tid) for tid in self.tasks}
             entry = self.policy.next_entry(sched)
@@ -195,7 +229,7 @@ class LiveRuntime:
             self._fault_in(task)
             for _ in range(self.steps_per_slice):
                 step = self._step_counter[entry.task_id]
-                task.run_step(step)
+                self.outputs[entry.task_id].append(task.run_step(step))
                 self._step_counter[entry.task_id] += 1
                 self.stats.steps[entry.task_id] += 1
                 if helper.queue:

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas TPU kernels, each as <name>/kernel.py plus a pure-jnp oracle in
+# <name>/ref.py. Callers pass ``interpret=True`` explicitly off the chip;
+# nothing here picks interpret mode for them.

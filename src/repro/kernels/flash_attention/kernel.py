@@ -1,6 +1,6 @@
 """Tiled online-softmax attention (prefill hot spot).
 
-Grid = (batch*kv_heads, q_groups, q_blocks); the kernel loops over KV blocks
+Grid = (batch*kv_heads, q_blocks); the kernel loops over KV blocks
 with running max/denominator so the (Sq, Skv) score matrix never leaves
 VMEM-tile granularity. Supports GQA (q heads grouped per kv head), causal
 masking, and a sliding window (recurrentgemma's local attention).
@@ -23,30 +23,31 @@ NEG_INF = -1e30
 
 
 def _fa_kernel(
-    q_ref,  # (1, bq, g, d)
+    q_ref,  # (1, 1, g*bq, d) — rows ordered (group, position)
     k_ref,  # (1, skv, d)
     v_ref,  # (1, skv, d)
-    o_ref,  # (1, bq, g, d)
+    o_ref,  # (1, 1, g*bq, d)
     *,
+    block_q: int,
     block_kv: int,
     causal: bool,
     window: int,
     sm_scale: float,
 ):
-    bq = q_ref.shape[1]
-    g = q_ref.shape[2]
-    d = q_ref.shape[3]
+    rows, d = q_ref.shape[2], q_ref.shape[3]
     skv = k_ref.shape[1]
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # (bq, g, d)
-    q2 = q.reshape(bq * g, d)
+    q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # (g*bq, d)
 
-    m = jnp.full((bq * g, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((bq * g, 1), jnp.float32)
-    acc = jnp.zeros((bq * g, d), jnp.float32)
+    m = jnp.full((rows, 1), NEG_INF, jnp.float32)
+    l = jnp.zeros((rows, 1), jnp.float32)
+    acc = jnp.zeros((rows, d), jnp.float32)
 
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, g), 0)
-    q_pos = q_pos.reshape(bq * g, 1)
+    # row r holds query position r % bq of group r // bq; built without a
+    # reshape, which Mosaic cannot lower for a (bq, g) -> (bq*g, 1) cast
+    q_pos = qi * block_q + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), block_q
+    )
 
     n_kv = skv // block_kv
 
@@ -54,7 +55,7 @@ def _fa_kernel(
         m, l, acc = carry
         k = k_ref[0, pl.ds(i * block_kv, block_kv), :].astype(jnp.float32)
         v = v_ref[0, pl.ds(i * block_kv, block_kv), :].astype(jnp.float32)
-        s = q2 @ k.T  # (bq*g, block_kv)
+        s = q @ k.T  # (g*bq, block_kv)
         kv_pos = i * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_kv), 1
         )
@@ -72,8 +73,7 @@ def _fa_kernel(
         return m_new, l, acc
 
     m, l, acc = jax.lax.fori_loop(0, n_kv, body, (m, l, acc))
-    out = acc / jnp.maximum(l, 1e-30)
-    o_ref[0] = out.reshape(bq, g, d).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -97,15 +97,22 @@ def flash_attention(
     bkv = min(block_kv, skv)
     assert sq % bq == 0 and skv % bkv == 0
 
-    # layout: fold q heads into (B*Hkv) batch; group dim g stays with q
-    qg = q.reshape(b, sq, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(b * hkv, sq, g, d)
+    # layout: fold q heads into (B*Hkv) batch; each q block's g heads are
+    # stacked as one (g*bq, d) tile so the kernel needs no in-VMEM reshape
+    nq = sq // bq
+    qg = (
+        q.reshape(b, nq, bq, hkv, g, d)
+        .transpose(0, 3, 1, 4, 2, 5)
+        .reshape(b * hkv, nq, g * bq, d)
+    )
     kg = k.transpose(0, 2, 1, 3).reshape(b * hkv, skv, d)
     vg = v.transpose(0, 2, 1, 3).reshape(b * hkv, skv, d)
 
-    grid = (b * hkv, sq // bq)
+    grid = (b * hkv, nq)
     out = pl.pallas_call(
         functools.partial(
             _fa_kernel,
+            block_q=bq,
             block_kv=bkv,
             causal=causal,
             window=window,
@@ -113,14 +120,16 @@ def flash_attention(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, g, d), lambda bh, qi: (bh, qi, 0, 0)),
+            pl.BlockSpec((1, 1, g * bq, d), lambda bh, qi: (bh, qi, 0, 0)),
             pl.BlockSpec((1, skv, d), lambda bh, qi: (bh, 0, 0)),
             pl.BlockSpec((1, skv, d), lambda bh, qi: (bh, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, g, d), lambda bh, qi: (bh, qi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hkv, sq, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g * bq, d), lambda bh, qi: (bh, qi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * hkv, nq, g * bq, d), q.dtype),
         interpret=interpret,
     )(qg, kg, vg)
     return (
-        out.reshape(b, hkv, sq, g, d).transpose(0, 2, 1, 3, 4).reshape(b, sq, h, d)
+        out.reshape(b, hkv, nq, g, bq, d)
+        .transpose(0, 2, 4, 1, 3, 5)
+        .reshape(b, sq, h, d)
     )

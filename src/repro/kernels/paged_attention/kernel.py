@@ -8,8 +8,9 @@ resident working set is its page list and the runtime can predict it (T2:
 linear in the current sequence length, §5.1's KV-cache example).
 
 Grid = (B, Hkv). The page loop walks only the pages < current length,
-accumulating online softmax. Pages are gathered from the pool via dynamic
-indices (PrefetchScalarGridSpec-style scalar prefetch of the page table).
+accumulating online softmax. The pool stays in HBM (``pl.ANY``); each page
+is DMA'd into a VMEM tile by dynamic index (scalar prefetch of the page
+table).
 """
 from __future__ import annotations
 
@@ -31,6 +32,9 @@ def _pa_kernel(
     pool_k_ref,  # (n_pages, pt, d)   [whole pool, ANY memory]
     pool_v_ref,
     o_ref,  # (1, 1, g, d)
+    k_buf,  # VMEM (pt, d) landing tile for one K page
+    v_buf,
+    sem,  # DMA semaphores (2,)
     *,
     page_tokens: int,
     max_pages: int,
@@ -45,8 +49,15 @@ def _pa_kernel(
     def body(p, carry):
         m, l, acc = carry
         page_id = ptab_ref[b, p]
-        k = pool_k_ref[page_id].astype(jnp.float32)  # (pt, d)
-        v = pool_v_ref[page_id].astype(jnp.float32)
+        # the pool stays in HBM; each page is DMA'd into VMEM before use
+        k_copy = pltpu.make_async_copy(pool_k_ref.at[page_id], k_buf, sem.at[0])
+        v_copy = pltpu.make_async_copy(pool_v_ref.at[page_id], v_buf, sem.at[1])
+        k_copy.start()
+        v_copy.start()
+        k_copy.wait()
+        v_copy.wait()
+        k = k_buf[...].astype(jnp.float32)  # (pt, d)
+        v = v_buf[...].astype(jnp.float32)
         s = q @ k.T  # (g, pt)
         pos = p * page_tokens + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_tokens), 1
@@ -98,6 +109,11 @@ def paged_attention(
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d), lambda i, *_: (i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((pt, d), pool_k.dtype),
+                pltpu.VMEM((pt, d), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
         )
         out = pl.pallas_call(
             functools.partial(

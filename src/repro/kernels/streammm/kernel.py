@@ -117,16 +117,20 @@ def stream_matmul_int8(
     assert scales.shape == (k // bk, n), (scales.shape, (k // bk, n))
     n_k = k // bk
     grid = (m // bm, n // bn, n_k)
+    # a (1, bn) block of the 2-D scales is refused by Mosaic (second-minor
+    # block dim must be a multiple of 8 or the whole axis); a leading
+    # squeezed k-block axis over (n_k, 1, N) leaves a legal (1, bn) tile
+    scales3 = scales.reshape(n_k, 1, n)
     return pl.pallas_call(
         functools.partial(_mm_int8_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((None, 1, bn), lambda i, j, kk: (kk, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, w_q, scales)
+    )(x, w_q, scales3)

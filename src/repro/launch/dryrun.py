@@ -101,9 +101,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
             compiled = lowered.compile()
             t_compile = time.time() - t0
         ma = compiled.memory_analysis()
-        ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
+        ca = compiled.cost_analysis()
         hlo = compiled.as_text()
         hc = analyze_hlo(hlo)
         rec.update(

@@ -12,24 +12,21 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; omit it elsewhere (Auto is
-    the default there anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (elastic re-mesh, tests on few host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        tuple(shape),
+        tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def dp_axes(mesh) -> tuple:

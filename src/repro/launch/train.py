@@ -31,6 +31,10 @@ def main():
             f"--xla_force_host_platform_device_count={args.devices}"
         )
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -31,13 +31,11 @@ def test_oversubscribed_outputs_match_baseline(tasks):
 
     for t in tasks:
         assert rt.stats.steps[t.task_id] == 8
-    # outputs are reproducible by re-running: compare against fresh runs
+    # what the oversubscribed runtime produced equals the all-resident run
     for t in tasks:
-        for s in t.segments:
-            if s.device is None:
-                s.device = jax.device_put(s.host)
-        again = [t.run_step(i) for i in range(8)]
-        for a, b in zip(baseline[t.task_id], again):
+        served = rt.outputs[t.task_id]
+        assert len(served) == 8
+        for a, b in zip(baseline[t.task_id], served):
             np.testing.assert_array_equal(a, b)
 
 
