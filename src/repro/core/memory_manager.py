@@ -12,7 +12,6 @@ ordered) — completing the *extended context switch*.
 from __future__ import annotations
 
 import dataclasses
-import time
 from bisect import bisect_left
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence, Set, Tuple
@@ -45,7 +44,6 @@ class SwitchReport:
     migration: "RunMigration | MigrationResult"
     populated_pages: int
     evicted_pages: int
-    wall_clock_coordinator_s: float  # real measured Python time (Fig. 11)
     # the template-predicted cut for the quantum (the populate plan before
     # residency filtering) — read only by the telemetry prediction auditor;
     # empty on the legacy path, which plans from page lists, not runs
@@ -246,7 +244,6 @@ class Coordinator:
         keyed by absolute time); single-GPU callers may omit it."""
         if self.legacy:
             return self._on_context_switch_legacy(next_task, timeline)
-        wall0 = time.perf_counter()
         cuts = compute_cuts(timeline, self.helpers)
         first_runs = first_access_runs(self.helpers, cuts)
 
@@ -262,7 +259,6 @@ class Coordinator:
                 ),
                 populated_pages=0,
                 evicted_pages=0,
-                wall_clock_coordinator_s=time.perf_counter() - wall0,
                 predicted_runs=first_runs,
             )
 
@@ -284,14 +280,12 @@ class Coordinator:
             )
             if tiered is not None:
                 rep = self._report(
-                    wall0, madvise_us, tiered,
+                    madvise_us, tiered,
                     run_page_count(populated_runs), evicted_pages,
                 )
                 rep.predicted_runs = first_runs
                 return rep
-        rep = self._finish_switch_runs(
-            wall0, madvise_us, populated_runs, evicted_pages
-        )
+        rep = self._finish_switch_runs(madvise_us, populated_runs, evicted_pages)
         rep.predicted_runs = first_runs
         return rep
 
@@ -325,7 +319,6 @@ class Coordinator:
     ) -> SwitchReport:
         """Pre-incremental engine: rebuild every helper's future and the full
         set-based plan on every switch (O(queue depth x footprint))."""
-        wall0 = time.perf_counter()
         futures = {tid: h.future_rebuild() for tid, h in self.helpers.items()}
         plan = build_plan(timeline, futures)
 
@@ -339,7 +332,6 @@ class Coordinator:
                 ),
                 populated_pages=0,
                 evicted_pages=0,
-                wall_clock_coordinator_s=time.perf_counter() - wall0,
             )
 
         madvise_us = 0.0
@@ -349,11 +341,10 @@ class Coordinator:
             moved = self.pool.madvise(sorted(group))
             madvise_us += MADVISE_CALL_US + MADVISE_PER_PAGE_US * moved
         populated, evicted = self.pool.migrate(plan.first_access_order)
-        return self._finish_switch(wall0, madvise_us, populated, evicted)
+        return self._finish_switch(madvise_us, populated, evicted)
 
     def _finish_switch(
         self,
-        wall0: float,
         madvise_us: float,
         populated: List[int],
         evicted: List[int],
@@ -361,13 +352,10 @@ class Coordinator:
         migration = plan_population(
             self.platform, populated, len(evicted), self.pipelined, self.page_size
         )
-        return self._report(
-            wall0, madvise_us, migration, len(populated), len(evicted)
-        )
+        return self._report(madvise_us, migration, len(populated), len(evicted))
 
     def _finish_switch_runs(
         self,
-        wall0: float,
         madvise_us: float,
         populated_runs,
         evicted_pages: int,
@@ -377,19 +365,16 @@ class Coordinator:
             self.page_size,
         )
         return self._report(
-            wall0, madvise_us, migration, run_page_count(populated_runs),
-            evicted_pages,
+            madvise_us, migration, run_page_count(populated_runs), evicted_pages
         )
 
     def _report(
         self,
-        wall0: float,
         madvise_us: float,
         migration,
         populated_pages: int,
         evicted_pages: int,
     ) -> SwitchReport:
-        wall = time.perf_counter() - wall0
         self.total_madvise_us += madvise_us
         self.total_migration_us += migration.total_us
         self.total_populated += populated_pages
@@ -399,5 +384,4 @@ class Coordinator:
             migration=migration,
             populated_pages=populated_pages,
             evicted_pages=evicted_pages,
-            wall_clock_coordinator_s=wall,
         )

@@ -12,16 +12,23 @@ migrates segments with real ``jax.device_put`` / host copies.
 Correctness contract (tested): step outputs are bit-identical to an
 all-resident baseline, because MSched migration is semantically transparent —
 exactly the paper's OS-level transparency claim.
+
+Every phase of the live path writes a ``jax.profiler.TraceAnnotation`` span
+named ``msched.*``, with its counts as the span's stats (``docs/
+observability.md``, "Live path spans"). With no profiler running a span
+costs about a microsecond, so they are always on.
 """
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config
 from repro.core.commands import Command, kernel
@@ -34,6 +41,18 @@ from repro.core.scheduler import RoundRobinPolicy, SchedTask
 from repro.core.templates import analyze_traces
 from repro.core.timeline import TaskTimeline
 from repro.core.hardware import TPU_V5E
+
+
+def named_step(fns):
+    """The jitted one-token decode step of ``fns``'s model, named
+    ``decode_step_<arch>`` so that each model's step program carries its
+    architecture in the trace's ``XLA Modules`` line."""
+
+    def step(params, tokens):
+        return fns.forward(params, {"tokens": tokens})
+
+    step.__name__ = step.__qualname__ = "decode_step_" + re.sub(r"\W", "_", fns.cfg.name)
+    return jax.jit(step)
 
 
 @dataclasses.dataclass
@@ -79,7 +98,7 @@ class LiveModelTask:
         self.tokens = jnp.ones((1, 1), jnp.int32)
         self.pos = 0
         self.kv_buf = self.space.malloc(1 << 20, "kv")
-        self._step = jax.jit(lambda p, t: self.fns.forward(p, {"tokens": t}))
+        self._step = named_step(self.fns)
 
     # -- command stream (the helper intercepts these) -----------------------
     def next_commands(self, step_idx: int) -> List[Command]:
@@ -94,10 +113,13 @@ class LiveModelTask:
 
     # -- execution -----------------------------------------------------------
     def run_step(self, rng_step: int) -> np.ndarray:
-        params = self.resident_params()
-        tok = jnp.asarray([[1 + (rng_step % 13)]], jnp.int32)
-        out = self._step(params, tok)
-        return np.asarray(out)
+        with TraceAnnotation("msched.step", task=self.task_id, step=rng_step):
+            with TraceAnnotation("msched.step.dispatch"):
+                params = self.resident_params()
+                tok = jnp.asarray([[1 + (rng_step % 13)]], jnp.int32)
+                out = self._step(params, tok)
+            with TraceAnnotation("msched.step.logits"):
+                return np.asarray(out)
 
     def resident_params(self):
         leaves = []
@@ -123,6 +145,7 @@ class LiveStats:
     in_bytes: Dict[int, int]  # host -> device
     out_bytes: Dict[int, int]  # device -> host (evictions)
     faults: Dict[int, int]  # demand faults
+    # host time of each switch, planning through the fetched arrays' arrival
     switch_wall_s: List[float]
 
     @property
@@ -172,6 +195,8 @@ class LiveRuntime:
             [],
         )
         self._step_counter = dict.fromkeys(ids, 0)
+        # serial number of the slice run last (the ``msched.slice`` stat)
+        self.last_slice = -1
         # logits of every step the last run() served, per task, in step order
         self.outputs: Dict[int, List[np.ndarray]] = {}
 
@@ -180,30 +205,40 @@ class LiveRuntime:
         """Make device arrays mirror the pool's residency decisions: a
         segment is on-device iff all of its pages are pool-resident. Every
         eviction runs before any host->device copy, so the device never holds
-        the outgoing and the incoming working sets at once."""
-        fetch = []
+        the outgoing and the incoming working sets at once. Returns once the
+        fetched arrays are on the device."""
+        fetch, evict = [], []
         for task in self.tasks.values():
-            tid = task.task_id
             for seg in task.segments:
                 pages = task.space.pages_of_extent((seg.base, seg.nbytes))
                 resident = all(self.pool.resident(p) for p in pages)
                 if resident and seg.device is None:
-                    fetch.append((tid, seg))
+                    fetch.append((task.task_id, seg))
                 elif not resident and seg.device is not None:
+                    evict.append((task.task_id, seg))
+        if evict:
+            nbytes = sum(seg.nbytes for _, seg in evict)
+            with TraceAnnotation("msched.evict", nbytes=nbytes, segments=len(evict)):
+                for tid, seg in evict:
                     seg.host = np.asarray(seg.device)  # D2H eviction
                     seg.device = None
                     self.stats.out_bytes[tid] += seg.nbytes
-        for tid, seg in fetch:
-            seg.device = jax.device_put(seg.host)  # H2D
-            self.stats.in_bytes[tid] += seg.nbytes
+        if fetch:
+            nbytes = sum(seg.nbytes for _, seg in fetch)
+            with TraceAnnotation("msched.fetch", nbytes=nbytes, segments=len(fetch)):
+                for tid, seg in fetch:
+                    seg.device = jax.device_put(seg.host)  # H2D
+                    self.stats.in_bytes[tid] += seg.nbytes
+                jax.block_until_ready([seg.device for _, seg in fetch])
 
     def _fault_in(self, task: LiveModelTask) -> None:
         """Demand-paging fallback: any still-missing segment faults in."""
         for seg in task.segments:
             if seg.device is None:
-                pages = list(task.space.pages_of_extent((seg.base, seg.nbytes)))
-                self.pool.migrate(pages)
-                self._sync_residency()
+                with TraceAnnotation("msched.fault_service", task=task.task_id, nbytes=seg.nbytes):
+                    pages = list(task.space.pages_of_extent((seg.base, seg.nbytes)))
+                    self.pool.migrate(pages)
+                    self._sync_residency()
                 self.stats.faults[task.task_id] += 1
 
     # -- main loop -------------------------------------------------------------
@@ -213,25 +248,36 @@ class LiveRuntime:
             sched = {tid: SchedTask(tid) for tid in self.tasks}
             entry = self.policy.next_entry(sched)
             timeline = TaskTimeline([entry] + self.policy.timeline(sched).entries)
-            task = self.tasks[entry.task_id]
-            helper = self.helpers[entry.task_id]
-            # refill the async window
-            while len(helper.queue) < 2 * self.steps_per_slice:
-                for cmd in task.next_commands(
-                    self._step_counter[entry.task_id] + len(helper.queue)
-                ):
-                    helper.launch(cmd)
-            # extended context switch: proactive working-set migration
-            t0 = time.perf_counter()
-            self.coordinator.on_context_switch(entry.task_id, timeline)
-            self._sync_residency()
-            self.stats.switch_wall_s.append(time.perf_counter() - t0)
-            self._fault_in(task)
-            for _ in range(self.steps_per_slice):
-                step = self._step_counter[entry.task_id]
-                self.outputs[entry.task_id].append(task.run_step(step))
-                self._step_counter[entry.task_id] += 1
-                self.stats.steps[entry.task_id] += 1
-                if helper.queue:
-                    helper.pop()
+            self.last_slice += 1
+            with TraceAnnotation("msched.slice", task=entry.task_id, slice=self.last_slice):
+                self._run_slice(entry.task_id, timeline)
         return self.stats
+
+    def _run_slice(self, tid: int, timeline: TaskTimeline) -> None:
+        task = self.tasks[tid]
+        helper = self.helpers[tid]
+        # refill the async window
+        while len(helper.queue) < 2 * self.steps_per_slice:
+            for cmd in task.next_commands(self._step_counter[tid] + len(helper.queue)):
+                helper.launch(cmd)
+        # extended context switch: proactive working-set migration
+        in0, out0 = self.stats.migrated_in_bytes, self.stats.migrated_out_bytes
+        t0 = time.perf_counter()
+        with TraceAnnotation("msched.switch", task=tid) as span:
+            with TraceAnnotation("msched.plan") as plan:
+                report = self.coordinator.on_context_switch(tid, timeline)
+                plan.set_metadata(pages_in=report.populated_pages, pages_out=report.evicted_pages)
+            self._sync_residency()
+            span.set_metadata(
+                in_bytes=self.stats.migrated_in_bytes - in0,
+                out_bytes=self.stats.migrated_out_bytes - out0,
+            )
+        self.stats.switch_wall_s.append(time.perf_counter() - t0)
+        self._fault_in(task)
+        for _ in range(self.steps_per_slice):
+            step = self._step_counter[tid]
+            self.outputs[tid].append(task.run_step(step))
+            self._step_counter[tid] += 1
+            self.stats.steps[tid] += 1
+            if helper.queue:
+                helper.pop()

@@ -21,10 +21,19 @@ from repro.core.runtime import LiveModelTask, LiveRuntime
 
 @dataclasses.dataclass
 class Request:
+    """One request; the server stamps it on ``time.perf_counter``'s clock
+    when it is submitted, when the slice that answers it starts and when it
+    is answered, and records that slice's serial number (the ``slice`` stat
+    of its ``msched.slice`` span)."""
+
     model: int
     arrival_s: float
     tokens: int = 1
+    submitted_s: Optional[float] = None
     # filled in when the request is answered
+    started_s: Optional[float] = None
+    answered_s: Optional[float] = None
+    slice: Optional[int] = None
     step: Optional[int] = None  # the model's decode step that served it
     logits: Optional[np.ndarray] = None
 
@@ -32,16 +41,9 @@ class Request:
 @dataclasses.dataclass
 class ServeStats:
     served: Dict[int, int]
-    latencies_s: Dict[int, List[float]]
     migrated_in_bytes: int
     migrated_out_bytes: int
     demand_faults: int
-
-    def p99(self, model: int) -> float:
-        xs = sorted(self.latencies_s.get(model, []))
-        if not xs:
-            return 0.0
-        return xs[min(len(xs) - 1, int(0.99 * len(xs)))]
 
 
 class MultiModelServer:
@@ -73,6 +75,7 @@ class MultiModelServer:
         }
 
     def submit(self, req: Request) -> None:
+        req.submitted_s = time.perf_counter()
         self.queues[req.model].append(req)
 
     def serve(
@@ -84,9 +87,7 @@ class MultiModelServer:
         runs out; requests still queued then are left in ``self.queues``.
         ``on_slice(model)`` is called after each slice's requests are
         answered."""
-        stats = ServeStats(
-            {m: 0 for m in self.queues}, {m: [] for m in self.queues}, 0, 0, 0
-        )
+        stats = ServeStats({m: 0 for m in self.queues}, 0, 0, 0)
         t_end = time.perf_counter() + wall_budget_s
         rt = self.runtime
         while time.perf_counter() < t_end and any(self.queues.values()):
@@ -96,15 +97,16 @@ class MultiModelServer:
             # run one slice for that model via the MSched runtime
             before = rt.stats.steps[model]
             rt.policy._rr = [model] + [m for m in rt.tasks if m != model]
+            started = time.perf_counter()
             rt.run(total_slices=1)
             now = time.perf_counter()
             outputs = rt.outputs[model]
             for i in range(min(len(outputs), len(self.queues[model]))):
                 req = self.queues[model].popleft()
+                req.started_s, req.answered_s, req.slice = started, now, rt.last_slice
                 req.step = before + i
                 req.logits = outputs[i]
                 stats.served[model] += 1
-                stats.latencies_s[model].append(now - req.arrival_s)
             if on_slice is not None:
                 on_slice(model)
         stats.migrated_in_bytes = rt.stats.migrated_in_bytes

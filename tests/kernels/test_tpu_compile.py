@@ -13,6 +13,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.runtime import named_step
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.paged_attention.kernel import paged_attention
 from repro.kernels.streammm.kernel import stream_matmul, stream_matmul_int8
@@ -114,8 +115,7 @@ def test_full_width_decode_step_compiles_for_v5e(arch, one_chip):
         lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), params
     )
     tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
-    step = jax.jit(lambda p, t: fns.forward(p, {"tokens": t}))
-    compiled = step.lower(params, tok).compile()
+    compiled = named_step(fns).lower(params, tok).compile()
     mem = compiled.memory_analysis()
     weights = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
     assert mem.argument_size_in_bytes >= weights
