@@ -120,7 +120,7 @@ def run_smoke(
         log(
             f"model {tid} {task.cfg.name}: answered {stats.served[tid]}, "
             f"host->device {rt.stats.in_bytes[tid]} B, "
-            f"device->host {rt.stats.out_bytes[tid]} B, "
+            f"evicted {rt.stats.out_bytes[tid]} B, "
             f"demand faults {rt.stats.faults[tid]}"
         )
     log(f"set-up seconds (host clock, incl. init and compiles): {setup_s}")
@@ -146,7 +146,7 @@ def run_smoke(
     unanswered = sum(req.logits is None for req in requests)
     _check(not unanswered, f"{unanswered} of {n_requests} requests unanswered")
     _check(stats.migrated_in_bytes > 0, "no bytes moved host->device")
-    _check(stats.migrated_out_bytes > 0, "no bytes moved device->host")
+    _check(stats.migrated_out_bytes > 0, "the pool evicted nothing")
     mismatched = []
     for req in requests:
         ref = reference[req.model][req.step]

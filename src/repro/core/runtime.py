@@ -3,11 +3,13 @@
 Each task is a real model from the zoo (at its published widths, or its
 ``.reduced()`` cut for CPU tests) whose parameters are
 page-granular segments in a task address space. "HBM" is a budgeted device
-pool: resident segments are ``jax.Array``s, evicted segments live as host
-numpy copies. On every context switch the MSched coordinator predicts the
-next task's working set (template predictor over the decode command stream,
-including the growing KV slice), enforces the OPT eviction order, and
-migrates segments with real ``jax.device_put`` / host copies.
+pool: every segment keeps a read-only host numpy array, and a resident one
+also a ``jax.Array`` put from it. On every context switch the MSched
+coordinator predicts the next task's working set (template predictor over
+the decode command stream, including the growing KV slice), enforces the OPT
+eviction order, and migrates segments with real ``jax.device_put``s. An
+eviction drops the device array: it equals the host array bit for bit, so
+nothing is copied back.
 
 Correctness contract (tested): step outputs are bit-identical to an
 all-resident baseline, because MSched migration is semantically transparent —
@@ -57,11 +59,17 @@ def named_step(fns):
 
 @dataclasses.dataclass
 class Segment:
+    """One weight leaf of a task. Weights are read-only: the step returns
+    only logits and JAX arrays are immutable, so a resident ``device`` copy
+    equals ``host`` for as long as it lives, and eviction drops it. A
+    writable segment (a KV cache) would need a write-back on eviction,
+    which no segment has today."""
+
     path: str
     base: int
     nbytes: int
-    host: np.ndarray  # authoritative host copy when evicted
-    device: Optional[jax.Array] = None  # resident copy
+    host: np.ndarray  # authoritative, never rewritten by migration
+    device: Optional[jax.Array] = None  # resident copy, put from ``host``
 
 
 class LiveModelTask:
@@ -143,7 +151,7 @@ class LiveStats:
 
     steps: Dict[int, int]
     in_bytes: Dict[int, int]  # host -> device
-    out_bytes: Dict[int, int]  # device -> host (evictions)
+    out_bytes: Dict[int, int]  # evicted from the device (dropped, not copied)
     faults: Dict[int, int]  # demand faults
     # host time of each switch, planning through the fetched arrays' arrival
     switch_wall_s: List[float]
@@ -203,9 +211,11 @@ class LiveRuntime:
     # -- real data movement ---------------------------------------------------
     def _sync_residency(self) -> None:
         """Make device arrays mirror the pool's residency decisions: a
-        segment is on-device iff all of its pages are pool-resident. Every
-        eviction runs before any host->device copy, so the device never holds
-        the outgoing and the incoming working sets at once. Returns once the
+        segment is on-device iff all of its pages are pool-resident. An
+        eviction drops the segment's device array, which frees its buffer at
+        once (the step has synchronised on its logits), and every eviction
+        runs before any host->device copy, so the device never holds the
+        outgoing and the incoming working sets at once. Returns once the
         fetched arrays are on the device."""
         fetch, evict = [], []
         for task in self.tasks.values():
@@ -220,8 +230,7 @@ class LiveRuntime:
             nbytes = sum(seg.nbytes for _, seg in evict)
             with TraceAnnotation("msched.evict", nbytes=nbytes, segments=len(evict)):
                 for tid, seg in evict:
-                    seg.host = np.asarray(seg.device)  # D2H eviction
-                    seg.device = None
+                    seg.device = None  # read-only: host already holds these bytes
                     self.stats.out_bytes[tid] += seg.nbytes
         if fetch:
             nbytes = sum(seg.nbytes for _, seg in fetch)

@@ -66,7 +66,7 @@ def test_smoke_body_fails_over_budget(smoke, monkeypatch):
 
 
 def test_smoke_body_fails_without_evictions(smoke):
-    with pytest.raises(smoke.SmokeFailure, match="device->host"):
+    with pytest.raises(smoke.SmokeFailure, match="evicted nothing"):
         smoke.run_smoke(smoke.ARCHS[:1], reduced=True, page_size=4096, log=[].append)
 
 
