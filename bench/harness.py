@@ -12,6 +12,9 @@ mix or metric is found by its name in ``BENCHMARK.json``:
   ``<base>.<variant>`` (one quantity that moves a different end-to-end
   metric in different cells) is read by ``<base>.py`` unless it has a file
   of its own.
+- ``bench/families/<model_type>.py``, named by each model's ``model_type``
+  in its configuration: the model's parameter layout, reference and step
+  cost (``bench/families/__init__.py``).
 
 The window drives the program's serving entry, ``MultiModelServer.submit``
 and ``.serve``; the harness submits between and after slices, from the
@@ -22,7 +25,9 @@ the window has closed and the program's device state is freed,
 weights, and the run is correct when no kept answer's logits lie further
 from the reference's than the configuration's limit (``logit_error``) and
 every weight the program holds, on the host and on the device, is the one
-the harness made (``bench.integrity``).
+the harness made (``bench.integrity``). A traced run also reduces the
+program's own ``msched.*`` spans in the window (``bench.spans``), for the
+readers in ``TraceSummary.spans``.
 """
 from __future__ import annotations
 
@@ -44,7 +49,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import costs, integrity, reference, trace_reduce, weights
+from bench import BenchError, costs, integrity, reference, spans, trace_reduce, weights
 from bench.record import Counters, RunRecord, Slice, TraceSummary, Tracked
 
 BENCH = Path(__file__).resolve().parent
@@ -55,14 +60,13 @@ TRACE_WINDOW_S = 10.0  # a traced run traces the window's first seconds
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-class BenchError(RuntimeError):
-    pass
-
-
 def step_token(step: int) -> int:
     """The token ``LiveModelTask.run_step`` feeds at the model's decode step
     ``step``: the step's input, which the program chooses."""
     return 1 + step % 13
+
+
+INPUTS = tuple(step_token(s) for s in range(13))  # one cycle of the program's inputs
 
 
 # -- finding a cell's files by name --------------------------------------------
@@ -392,13 +396,17 @@ def compare(
 ) -> Dict:
     """Every distinct answer against the float32 reference of its input.
     ``weights_mismatched`` counts the program's weight copies that differ
-    from the harness's (``bench.integrity.mismatched``)."""
+    from the harness's (``bench.integrity.mismatched``). While it holds each
+    model's seeded weights it also takes the model's step cost over the
+    program's inputs (``bench.costs.step_cost``), returned as ``costs``."""
     worst = 0.0
+    step_costs = {}
     for i, m in enumerate(models):
+        params = weights.generate(m, seeds[i])
+        step_costs[i] = costs.step_cost(m, params, INPUTS)
         toks = sorted(tok for model, tok in sess.answers if model == i)
         if not toks:
             continue
-        params = weights.generate(m, seeds[i])
         ref = reference.logits(m, params, toks)
         del params
         if not np.isfinite(ref).all():
@@ -421,7 +429,7 @@ def compare(
         and sess.n_answers >= 1
         and weights_mismatched == 0
     )
-    return {"correct": correct, "checks": checks}
+    return {"correct": correct, "checks": checks, "costs": step_costs}
 
 
 # -- one run ---------------------------------------------------------------------
@@ -434,6 +442,7 @@ def _summarize_trace(trace_dir: str) -> TraceSummary:
     busy = trace_reduce.busy_ns(tr, w0, w1)
     runs = trace_reduce.step_runs(tr, w0, w1)
     gaps = trace_reduce.idle_gaps(tr, w0, w1)
+    program = spans.load(path)
     return TraceSummary(
         window_s=(w1 - w0) / 1e9,
         busy_s=None if busy is None else busy / 1e9,
@@ -443,6 +452,7 @@ def _summarize_trace(trace_dir: str) -> TraceSummary:
             [f"{label} ({n} gaps)", ns / 1e9]
             for label, (n, ns) in sorted(gaps.items(), key=lambda x: -x[1][1])
         ][:10],
+        spans=spans.summarize(tr, program, w0, w1),
     )
 
 
@@ -516,7 +526,7 @@ def run_cell(
         slices=sess.slices,
         step_s=[dt for end, dt in sess.steps if end <= sess.t_end],
         trace=summary,
-        costs={i: costs.step_cost(m) for i, m in enumerate(models)},
+        costs=check["costs"],
         peaks=peaks,
     )
     out_metrics = {}

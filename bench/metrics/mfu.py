@@ -1,6 +1,7 @@
 """Useful FLOPs of the window's answered steps per second, over the chip's
-bf16 peak (%). A step's FLOPs are ``bench.costs.step_cost``'s; steps that
-answered nothing do not count."""
+bf16 peak (%). A step's FLOPs are ``bench.costs.step_cost``'s: the work its
+input routes to, whatever the program implements, as the mean over one
+cycle of the program's inputs. Steps that answered nothing do not count."""
 
 
 def read(rec):

@@ -1,7 +1,9 @@
 """The step programs' least time over their device time in the trace (%).
 
 A model's least time per step is max(FLOPs / peak FLOP/s, bytes / peak
-bytes/s) from ``bench.costs.step_cost``; at batch 1 the bytes bound it."""
+bytes/s) from ``bench.costs.step_cost``: the work the input routes to,
+whatever the program implements, as the mean over one cycle of the
+program's inputs. At batch 1 the bytes bound it."""
 
 
 def read(rec):

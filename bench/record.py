@@ -70,7 +70,8 @@ class Outcome:
 
 @dataclasses.dataclass
 class TraceSummary:
-    """The traced window as ``bench.trace_reduce`` reads it."""
+    """The traced window as ``bench.trace_reduce`` reads it, and the
+    program's own spans in it as ``bench.spans.summarize`` reduces them."""
 
     window_s: float
     busy_s: float
@@ -78,6 +79,7 @@ class TraceSummary:
     step_runs: Dict[int, tuple]
     device_ops: list
     idle_gaps: list
+    spans: dict
 
 
 @dataclasses.dataclass
@@ -93,7 +95,7 @@ class RunRecord:
     slices: List[Slice]
     step_s: List[float]
     trace: Optional[TraceSummary]
-    costs: Dict[int, tuple]  # model index -> (FLOPs, bytes) of one step
+    costs: Dict[int, tuple]  # model index -> mean (FLOPs, bytes) of one step (bench.costs)
     peaks: Dict[str, float]
 
     def answered_in_window(self) -> List[Tracked]:
@@ -123,6 +125,11 @@ class RunRecord:
         if not lat:
             return None
         return 1000.0 * percentile(sorted(lat), pct)
+
+    def span_number(self, name: str) -> Optional[float]:
+        """One of ``bench.spans.summarize``'s numbers of the traced window;
+        None untraced, or where the spans hold nothing to read."""
+        return None if self.trace is None else self.trace.spans.get(name)
 
     def median_step_ms(self) -> Optional[float]:
         return 1000.0 * float(np.median(self.step_s)) if self.step_s else None

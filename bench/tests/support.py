@@ -22,22 +22,10 @@ REDUCED_LIMIT = 0.15
 
 
 def reduce_model(m: dict) -> dict:
-    from repro.configs import get_config
+    """Model entry ``m`` at the program's ``.reduced()`` cut, by its family."""
+    from bench import families
 
-    c = get_config(m["arch"]).reduced()
-    m = dict(m, vocab_size=c.vocab_size)
-    if m["model_type"] == "mamba2":
-        s = c.ssm
-        m.update(d_model=c.d_model, n_layer=c.num_layers)
-        m["ssm_cfg"] = dict(m["ssm_cfg"], d_state=s.state_dim, d_conv=s.conv_width,
-                            expand=s.expand, headdim=s.head_dim, chunk_size=s.chunk)
-    else:
-        m.update(hidden_size=c.d_model, num_hidden_layers=c.num_layers, num_attention_heads=c.num_heads,
-                 num_key_value_heads=c.num_kv_heads, head_dim=c.head_dim, intermediate_size=c.d_ff)
-        if "scale_depth" in m:
-            m["scale_depth"] = c.num_layers ** 0.5
-            m["dim_model_base"] = c.d_model
-    return m
+    return families.load(m["model_type"]).reduce(m)
 
 
 PAGE = 1 << 20

@@ -30,6 +30,23 @@ def test_tied_head_counts_the_embedding_once():
     assert b_untied - b_tied == 2 * d  # the looked-up row
 
 
+# the published widths' costs, as step_roofline and mfu have read them since
+# the benchmark began
+FULL_WIDTH = {
+    "qwen3-1.7b": (3_440_902_144, 3_441_401_856),
+    "minicpm-2b": (5_449_388_544, 5_450_135_040),
+    "mamba2-1.3b": (2_686_451_712, 2_688_098_304),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(FULL_WIDTH))
+def test_full_width_costs_pinned(arch):
+    for config in ("trio-1.5x", "trio-fit"):
+        models = json.loads((ROOT / f"bench/configs/{config}.json").read_text())["models"]
+        (m,) = [m for m in models if m["arch"] == arch]
+        assert costs.step_cost(m) == FULL_WIDTH[arch]
+
+
 def test_peaks_known_and_unknown():
     assert costs.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError, match="no peaks"):

@@ -22,10 +22,11 @@ def _expected(cell, trace):
 
 
 # metrics that only the chip's trace gives: a CPU run has no TPU plane
-DEVICE_ONLY = {"step_roofline", "device_idle_share", "device_idle_share.open"}
+DEVICE_ONLY = {"step_roofline", "device_idle_share", "device_idle_share.open",
+               "step_idle_share.open", "step_idle_share.closed"}
 
 
-@pytest.mark.parametrize("cell,trace", [(c, False) for c in CELLS] + [(CELLS[0], True), (CELLS[1], True)])
+@pytest.mark.parametrize("cell,trace", [(c, False) for c in CELLS] + [(c, True) for c in CELLS])
 def test_run_cell_result_line(reduced_root, cell, trace):
     r = harness.run_cell(cell, 2**33 + 17, 1.0, trace, time.perf_counter(),
                          root=reduced_root, reduced=True, peaks=CPU_PEAKS)

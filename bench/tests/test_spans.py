@@ -165,3 +165,32 @@ def test_load_reads_stats_from_a_recorded_trace(tmp_path):
     w0, w1 = tr.window(planes)
     assert w0 <= spans[0].start and spans[0].end <= w1
     assert all(len(e) == 3 for p in planes for evs in p["lines"].values() for e in evs)
+
+
+def _record(trace):
+    from bench.record import RunRecord
+
+    return RunRecord(models=[], seconds=1.0, setup_s=0.0, t0=0.0, t_end=1.0, requests=[], outcome=None,
+                     base=None, slices=[], step_s=[], trace=trace, costs={}, peaks={})
+
+
+def _summary(spans):
+    from bench.record import TraceSummary
+
+    return TraceSummary(window_s=1e-6, busy_s=0.0, step_runs={}, device_ops=[], idle_gaps=[],
+                        spans=sp.summarize(_trace(), spans, 0, 1000))
+
+
+@pytest.mark.parametrize("name", ["evict_share", "h2d_gbps", "plan_ms", "step_idle_share"])
+def test_span_metric_readers(name):
+    """Each reader gives the window's number from the run record's spans,
+    under either variant, and nothing untraced or where no span was written."""
+    from bench import harness
+
+    summary = _summary(SPANS)
+    assert summary.spans[name] is not None
+    for variant in ("open", "closed"):
+        reader = harness.load_reader(harness.ROOT, f"{name}.{variant}")
+        assert reader.read(_record(summary)) == summary.spans[name]
+        assert reader.read(_record(None)) is None
+        assert reader.read(_record(_summary([]))) is None
