@@ -5,11 +5,13 @@
 Builds a ``MultiModelServer`` over qwen3-1.7b, mamba2-1.3b and minicpm-2b at
 their published widths (random weights from fixed seeds, about 11.6 GiB)
 with a device pool of two thirds of that, at the platform's 4 MiB extent.
-It answers 24 requests, 8 per model, with 4 decode steps per slice, so every
-model gets at least two slices and weights are evicted and fetched back.
+It answers 24 requests, 8 per model, with 4 decode steps per slice, decoded
+as one batch, so every model gets at least two slices and weights are
+evicted and fetched back.
 
 Every answer's logits must equal, bit for bit, those of the same decode step
-run on the same chip with that model alone and all of its weights resident.
+run on the same chip, in the same batch of the same step program, with that
+model alone and all of its weights resident.
 The script fails when a request is left unanswered, when no bytes moved in
 either direction, when the logits differ, when the device holds more than
 the pool budget of weights after a slice, or when JAX finds no TPU. The
@@ -50,12 +52,18 @@ def _allocator_bytes(dev):
 
 
 def _all_resident_logits(task, steps):
-    """Logits of ``steps`` with the task alone and every segment resident."""
+    """Logits of decode steps ``0 .. steps - 1`` with the task alone and every
+    segment resident, through the batched step the server's slices run: one
+    ``run_step`` per ``task.rows`` steps."""
     import jax
 
     for s in task.segments:
         s.device = jax.device_put(s.host)
-    out = {i: task.run_step(i) for i in steps}
+    out = {}
+    for first in range(0, steps, task.rows):
+        logits = task.run_step(first)
+        for k in range(task.rows):
+            out[first + k] = logits[k : k + 1]
     for s in task.segments:
         s.device = None
     return out
@@ -93,9 +101,9 @@ def run_smoke(
         f"{server.budget_bytes} B"
     )
     after_init = _allocator_bytes(dev)
-    steps = range(REQUESTS_PER_MODEL)
     reference = {
-        tid: _all_resident_logits(task, steps) for tid, task in rt.tasks.items()
+        tid: _all_resident_logits(task, REQUESTS_PER_MODEL)
+        for tid, task in rt.tasks.items()
     }
     setup_s = time.perf_counter() - t0
     after_setup = _allocator_bytes(dev)

@@ -27,6 +27,7 @@ def main():
     dt = time.time() - t0
 
     print(f"steps per task: {stats.steps}")
+    print(f"step programs per task: {stats.step_calls} ({rt.steps_per_slice} steps each)")
     print(f"proactively migrated in : {stats.migrated_in_bytes/2**20:8.1f} MiB")
     print(f"evicted to host         : {stats.migrated_out_bytes/2**20:8.1f} MiB")
     print(f"demand faults (F- path) : {stats.demand_faults}")

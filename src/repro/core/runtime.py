@@ -46,9 +46,10 @@ from repro.core.hardware import TPU_V5E
 
 
 def named_step(fns):
-    """The jitted one-token decode step of ``fns``'s model, named
-    ``decode_step_<arch>`` so that each model's step program carries its
-    architecture in the trace's ``XLA Modules`` line."""
+    """The jitted one-token decode step of ``fns``'s model over a ``(rows,
+    1)`` batch of tokens, named ``decode_step_<arch>`` so that each model's
+    step program carries its architecture in the trace's ``XLA Modules``
+    line."""
 
     def step(params, tokens):
         return fns.forward(params, {"tokens": tokens})
@@ -102,9 +103,8 @@ class LiveModelTask:
             host = np.asarray(leaf)
             buf = self.space.malloc(max(host.nbytes, 1), path)
             self.segments.append(Segment(path, buf.base, host.nbytes, host))
-        # decode state
-        self.tokens = jnp.ones((1, 1), jnp.int32)
-        self.pos = 0
+        # decode steps per ``run_step`` call; ``LiveRuntime`` sets its slice length
+        self.rows = 1
         self.kv_buf = self.space.malloc(1 << 20, "kv")
         self._step = named_step(self.fns)
 
@@ -112,20 +112,25 @@ class LiveModelTask:
     def next_commands(self, step_idx: int) -> List[Command]:
         exts = [(s.base, s.nbytes) for s in self.segments]
         exts.append((self.kv_buf.base, min(4096 * (step_idx + 1), self.kv_buf.size)))
+        # the predicted extent is 4096 x this argument: it stops at the buffer
         args = tuple(s.base for s in self.segments[:8]) + (
             self.kv_buf.base,
-            step_idx + 1,
+            min(step_idx + 1, self.kv_buf.size // 4096),
             4096,
         )
         return [kernel(f"{self.cfg.name}_step", args, 500.0, exts)]
 
     # -- execution -----------------------------------------------------------
-    def run_step(self, rng_step: int) -> np.ndarray:
-        with TraceAnnotation("msched.step", task=self.task_id, step=rng_step):
+    def run_step(self, step: int) -> np.ndarray:
+        """Decode steps ``step`` .. ``step + rows - 1`` in one call of the
+        jitted step, which reads each weight once for all of them. Row ``k``
+        of the returned ``(rows, 1, vocab)`` logits answers step ``step +
+        k``, whose input token is ``1 + (step + k) % 13``."""
+        with TraceAnnotation("msched.step", task=self.task_id, step=step, rows=self.rows):
             with TraceAnnotation("msched.step.dispatch"):
                 params = self.resident_params()
-                tok = jnp.asarray([[1 + (rng_step % 13)]], jnp.int32)
-                out = self._step(params, tok)
+                tok = 1 + np.arange(step, step + self.rows)[:, None] % 13
+                out = self._step(params, jnp.asarray(tok, jnp.int32))
             with TraceAnnotation("msched.step.logits"):
                 return np.asarray(out)
 
@@ -149,7 +154,8 @@ class LiveModelTask:
 class LiveStats:
     """Counters per task id; the totals over tasks are properties."""
 
-    steps: Dict[int, int]
+    steps: Dict[int, int]  # decode steps run
+    step_calls: Dict[int, int]  # step programs launched, ``steps_per_slice`` steps each
     in_bytes: Dict[int, int]  # host -> device
     out_bytes: Dict[int, int]  # evicted from the device (dropped, not copied)
     faults: Dict[int, int]  # demand faults
@@ -179,6 +185,9 @@ class LiveRuntime:
         steps_per_slice: int = 4,
     ):
         self.tasks = {t.task_id: t for t in tasks}
+        # a slice decodes its steps as one batch
+        for t in tasks:
+            t.rows = steps_per_slice
         # the pool pages at the tasks' extent, so they must share one
         (page_size,) = {t.space.page_size for t in tasks}
         self.page_size = page_size
@@ -196,6 +205,7 @@ class LiveRuntime:
         self.policy = RoundRobinPolicy(quantum_us=1000.0 * steps_per_slice)
         ids = list(self.tasks)
         self.stats = LiveStats(
+            dict.fromkeys(ids, 0),
             dict.fromkeys(ids, 0),
             dict.fromkeys(ids, 0),
             dict.fromkeys(ids, 0),
@@ -283,10 +293,11 @@ class LiveRuntime:
             )
         self.stats.switch_wall_s.append(time.perf_counter() - t0)
         self._fault_in(task)
-        for _ in range(self.steps_per_slice):
-            step = self._step_counter[tid]
-            self.outputs[tid].append(task.run_step(step))
-            self._step_counter[tid] += 1
-            self.stats.steps[tid] += 1
+        out = task.run_step(self._step_counter[tid])
+        self.stats.step_calls[tid] += 1
+        for k in range(task.rows):
+            self.outputs[tid].append(out[k : k + 1])
             if helper.queue:
                 helper.pop()
+        self._step_counter[tid] += task.rows
+        self.stats.steps[tid] += task.rows

@@ -73,9 +73,10 @@ def test_slice_holds_steps_which_hold_dispatch_and_logits(traced):
     slices = _named(spans, "msched.slice")
     assert [s[3]["slice"] for s in slices] == list(range(slices[0][3]["slice"], server.runtime.last_slice + 1))
     for sl in slices:
-        steps = [s for s in _named(spans, "msched.step") if _inside(s, sl)]
-        assert len(steps) == STEPS_PER_SLICE
-        assert {s[3]["task"] for s in steps} == {sl[3]["task"]}
+        # one step program decodes the slice's steps as one batch
+        (step,) = [s for s in _named(spans, "msched.step") if _inside(s, sl)]
+        assert step[3]["rows"] == STEPS_PER_SLICE
+        assert step[3]["task"] == sl[3]["task"]
         assert len([s for s in _named(spans, "msched.switch") if _inside(s, sl)]) == 1
     steps = _named(spans, "msched.step")
     assert all(any(_inside(s, sl) for sl in slices) for s in steps)
@@ -83,6 +84,8 @@ def test_slice_holds_steps_which_hold_dispatch_and_logits(traced):
         for child in ("msched.step.dispatch", "msched.step.logits"):
             assert len([s for s in _named(spans, child) if _inside(s, st)]) == 1
     assert len(_named(spans, "msched.step.dispatch")) == len(steps)
+    stats = server.runtime.stats
+    assert sum(stats.steps.values()) == STEPS_PER_SLICE * sum(stats.step_calls.values())
 
 
 def test_span_bytes_equal_the_counters(traced):

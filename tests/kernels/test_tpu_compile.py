@@ -3,7 +3,8 @@
 The TPU compiler refuses what interpret mode accepts (unaligned blocks,
 reshapes Mosaic cannot lay out, loads from HBM refs), so each kernel is
 compiled at qwen3-1.7b widths and each served model's one-token decode step
-at its published widths. Nothing runs; only the compiler is exercised.
+at its published widths, alone and as a slice's batch of 32. Nothing runs;
+only the compiler is exercised.
 """
 import os
 
@@ -106,18 +107,36 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("arch", ["qwen3-1.7b", "mamba2-1.3b", "minicpm-2b"])
-def test_full_width_decode_step_compiles_for_v5e(arch, one_chip):
-    # the step LiveModelTask jits: forward on one token, all weights resident
+SERVED = ["qwen3-1.7b", "mamba2-1.3b", "minicpm-2b"]
+
+
+def _compile_step(arch, rows, one_chip):
+    """The step LiveModelTask jits, at published widths over ``rows``
+    one-token inputs, all weights resident; returns (compiled, weight bytes)."""
     fns = build_model(get_config(arch))
     params = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
     params = jax.tree.map(
         lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), params
     )
-    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
+    tok = jax.ShapeDtypeStruct((rows, 1), jnp.int32, sharding=one_chip)
     compiled = named_step(fns).lower(params, tok).compile()
-    mem = compiled.memory_analysis()
     weights = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
+    return compiled, weights
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_full_width_decode_step_compiles_for_v5e(arch, one_chip):
+    compiled, weights = _compile_step(arch, 1, one_chip)
+    mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= weights
     # weights plus the step's own buffers fit one 16 GB chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_full_width_slice_batch_step_compiles_for_v5e(arch, one_chip):
+    # the benchmark's slice of 32 steps runs as one (32, 1) batch
+    compiled, weights = _compile_step(arch, 32, one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes < 16e9
