@@ -35,8 +35,6 @@ MODULES = [
     "cluster_oversub",
     "p2p_prefetch",
     "fault_recovery",
-    "kernels_bench",
-    "roofline_report",
 ]
 
 PROFILE_TOP_N = 20

@@ -9,8 +9,6 @@ from repro.configs.base import (
     ShapeSpec,
     SSMConfig,
     SHAPES,
-    SHAPE_ORDER,
-    cell_applicable,
 )
 
 from repro.configs import (  # noqa: E402
@@ -65,8 +63,6 @@ __all__ = [
     "SSMConfig",
     "ShapeSpec",
     "SHAPES",
-    "SHAPE_ORDER",
-    "cell_applicable",
     "get_config",
     "list_archs",
 ]

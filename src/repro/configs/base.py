@@ -4,7 +4,7 @@ Every assigned architecture is expressed as a frozen ``ModelConfig``. The same
 config object drives:
   * model construction (``repro.models.model.build_model``),
   * sharding rules (``repro.sharding.specs``),
-  * the dry-run input specs (``repro.launch.specs``),
+  * the smoke tests' input specs (``repro.launch.specs``),
   * the MSched workload generators (``repro.core.workloads``) — each config
     deterministically yields the command stream + ground-truth working sets
     that the paper's predictor/scheduler operate on.
@@ -87,7 +87,6 @@ class ModelConfig:
     schedule: str = "cosine"  # 'cosine' | 'wsd'
     remat: bool = True
     # capability flags
-    sub_quadratic: bool = False  # can run long_500k
     has_decode: bool = True  # False for encoder-only archs
     notes: str = ""
 
@@ -225,13 +224,3 @@ SHAPES = {
     "long_500k": ShapeSpec("long_500k", 524_288, 1, "decode"),
 }
 
-SHAPE_ORDER = ("train_4k", "prefill_32k", "decode_32k", "long_500k")
-
-
-def cell_applicable(cfg: ModelConfig, shape: ShapeSpec) -> Tuple[bool, str]:
-    """Whether (arch x shape) is runnable; else the documented skip reason."""
-    if shape.kind == "decode" and not cfg.has_decode:
-        return False, "encoder-only arch: no autoregressive decode step"
-    if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False, "pure full-attention arch: long_500k requires sub-quadratic attention"
-    return True, ""

@@ -11,6 +11,5 @@ CONFIG = ModelConfig(
     d_ff=0,  # no MLP; the mamba block is the whole layer
     vocab_size=50280,
     ssm=SSMConfig(state_dim=128, head_dim=64, conv_width=4, expand=2, chunk=256),
-    sub_quadratic=True,  # O(1) decode state
     notes="SSD chunked algorithm; attention-free; constant-size decode state",
 )

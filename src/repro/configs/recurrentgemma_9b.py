@@ -12,6 +12,5 @@ CONFIG = ModelConfig(
     vocab_size=256000,
     head_dim=256,
     rglru=RGLRUConfig(window=2048, pattern=("rec", "rec", "attn")),
-    sub_quadratic=True,  # recurrence + sliding-window attention
     notes="Griffin-style: 2 RG-LRU blocks : 1 local-attention block; MQA kv=1",
 )

@@ -1,39 +1,1 @@
-"""MSched core: proactive memory scheduling for accelerator multitasking.
-
-The paper's contribution as a composable library — see DESIGN.md.
-"""
-from repro.core.hardware import PLATFORMS, RTX3080, RTX5080, TPU_V5E  # noqa: F401
-from repro.core.hbm import HBMPool  # noqa: F401
-from repro.core.memory_manager import Coordinator, TaskHelper  # noqa: F401
-from repro.core.opt import (  # noqa: F401
-    PlannedAccess,
-    belady_reference,
-    belady_reference_scan,
-    build_plan,
-)
-from repro.core.pages import (  # noqa: F401
-    AddressSpace,
-    RunSet,
-    expand_runs,
-    merge_runs,
-    pages_to_runs,
-)
-from repro.core.planner import RunPlan, plan_switch  # noqa: F401
-from repro.core.predictor import (  # noqa: F401
-    AllocationPredictor,
-    OraclePredictor,
-    TemplatePredictor,
-    evaluate_accuracy,
-)
-from repro.core.profiler import profile_programs  # noqa: F401
-from repro.core.scheduler import PriorityPolicy, RoundRobinPolicy  # noqa: F401
-from repro.core.simulator import (  # noqa: F401
-    AdmissionController,
-    RequestRecord,
-    SimResult,
-    SimState,
-    TaskArrival,
-    simulate,
-)
-from repro.core.templates import analyze_traces, template_mix_table  # noqa: F401
-from repro.core.timeline import TaskTimeline, TimelineEntry  # noqa: F401
+"""MSched core: proactive memory scheduling for accelerator multitasking."""

@@ -1,8 +1,8 @@
 """Hardware constants.
 
 GPU-side constants are calibrated from the paper's own measurements (§3, §7.3)
-so the simulator reproduces its figures; TPU v5e constants drive the roofline
-analysis of the dry-run (§Roofline in EXPERIMENTS.md).
+so the simulator reproduces its figures; ``TPU_V5E`` is the live runtime's
+platform, whose 4 MiB page is the extent it migrates at.
 """
 from __future__ import annotations
 
@@ -98,16 +98,13 @@ H100_80G = Platform(
 NVLINK_A100_GBPS = 300.0
 NVLINK_H100_GBPS = 450.0
 
-# TPU v5e — the deployment target for the framework (roofline §Perf).
-TPU_V5E_PEAK_BF16_FLOPS = 197e12  # per chip
-TPU_V5E_HBM_GBPS = 819.0  # per chip
-TPU_V5E_ICI_GBPS = 50.0  # per link
+# TPU v5e — the chip the live runtime serves on.
 TPU_V5E_HBM_BYTES = 16 << 30
 
 TPU_V5E = Platform(
     name="tpu_v5e",
     hbm_bytes=TPU_V5E_HBM_BYTES,
-    page_size=4 << 20,  # TPU adaptation: 4 MiB extents (see DESIGN.md)
+    page_size=4 << 20,  # TPU adaptation: 4 MiB extents, not 4 KiB GPU pages
     fault_total_us=0.0,  # TPUs cannot fault: proactive scheduling is mandatory
     fault_transfer_us=0.0,
     d2h_gbps=32.0,  # host DMA

@@ -10,12 +10,11 @@ from __future__ import annotations
 from typing import Sequence
 
 from repro.core.trace import TraceStore
-from repro.core.workloads import TaskProgram
 
 
-def profile_programs(
-    programs: Sequence[TaskProgram], iters: int = 4
-) -> TraceStore:
+def profile_programs(programs: Sequence, iters: int = 4) -> TraceStore:
+    """Each program has a ``space`` (its ``AddressSpace``) and
+    ``iteration(it)``, the commands of iteration ``it``."""
     store = TraceStore()
     for prog in programs:
         for it in range(iters):

@@ -39,9 +39,8 @@ from repro.core.memory_manager import Coordinator, TaskHelper
 from repro.core.pages import AddressSpace
 from repro.core.predictor import TemplatePredictor
 from repro.core.profiler import profile_programs
-from repro.core.scheduler import RoundRobinPolicy, SchedTask
 from repro.core.templates import analyze_traces
-from repro.core.timeline import TaskTimeline
+from repro.core.timeline import TaskTimeline, TimelineEntry
 from repro.core.hardware import TPU_V5E
 
 
@@ -176,7 +175,12 @@ class LiveStats:
 
 
 class LiveRuntime:
-    """Round-robin multitasking with proactive working-set migration."""
+    """Round-robin multitasking with proactive working-set migration.
+
+    ``order`` is the run order: each slice runs its head, which then moves
+    to its tail, and the coordinator plans with that task followed by two
+    rounds of the new order, every entry ``quantum_us`` long. A server names
+    the next slice's task with ``put_first``."""
 
     def __init__(
         self,
@@ -202,7 +206,8 @@ class LiveRuntime:
             self.helpers[t.task_id] = h
             self.coordinator.register(h)
         self.steps_per_slice = steps_per_slice
-        self.policy = RoundRobinPolicy(quantum_us=1000.0 * steps_per_slice)
+        self.quantum_us = 1000.0 * steps_per_slice
+        self.order: List[int] = sorted(self.tasks)
         ids = list(self.tasks)
         self.stats = LiveStats(
             dict.fromkeys(ids, 0),
@@ -261,15 +266,22 @@ class LiveRuntime:
                 self.stats.faults[task.task_id] += 1
 
     # -- main loop -------------------------------------------------------------
+    def put_first(self, task_id: int) -> None:
+        """Make ``task_id`` the next slice's task, the others following in
+        id order."""
+        self.order = [task_id] + [t for t in sorted(self.tasks) if t != task_id]
+
     def run(self, total_slices: int = 12) -> LiveStats:
         self.outputs = {tid: [] for tid in self.tasks}
         for _ in range(total_slices):
-            sched = {tid: SchedTask(tid) for tid in self.tasks}
-            entry = self.policy.next_entry(sched)
-            timeline = TaskTimeline([entry] + self.policy.timeline(sched).entries)
+            tid = self.order.pop(0)
+            self.order.append(tid)
+            timeline = TaskTimeline(
+                [TimelineEntry(t, self.quantum_us) for t in [tid] + 2 * self.order]
+            )
             self.last_slice += 1
-            with TraceAnnotation("msched.slice", task=entry.task_id, slice=self.last_slice):
-                self._run_slice(entry.task_id, timeline)
+            with TraceAnnotation("msched.slice", task=tid, slice=self.last_slice):
+                self._run_slice(tid, timeline)
         return self.stats
 
     def _run_slice(self, tid: int, timeline: TaskTimeline) -> None:

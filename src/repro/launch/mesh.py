@@ -1,8 +1,7 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-Single pod: (data=16, model=16) — 256 chips (one v5e pod).
-Multi-pod:  (pod=2, data=16, model=16) — 512 chips; the ``pod`` axis maps to
-the DCN dimension and composes with ``data`` for batch/gradient reduction.
+A multi-pod mesh has a leading ``pod`` axis, which maps to the DCN dimension
+and composes with ``data`` for batch/gradient reduction.
 
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state.
@@ -10,14 +9,6 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-    )
 
 
 def make_mesh(shape, axes):

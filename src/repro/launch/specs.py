@@ -1,5 +1,5 @@
-"""Input specs per (arch × shape): ShapeDtypeStruct stand-ins for the dry-run
-and concrete random batches for smoke tests — same shapes, one source of truth.
+"""Input specs per (arch × shape): ShapeDtypeStruct specs and the concrete
+random batches for smoke tests built from them — one source of truth.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Step builders: train_step / prefill_step / serve_step per architecture."""
+"""Step builders: train_step / serve_step per architecture."""
 from __future__ import annotations
 
 import os
@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.model import ModelFns, build_model
+from repro.models.model import build_model
 from repro.optim import get_optimizer, get_schedule
 
 
@@ -17,12 +17,6 @@ def make_train_state(cfg: ModelConfig, rng):
     params = fns.init(rng)
     opt = get_optimizer(cfg.optimizer)
     return {"params": params, "opt": opt.init(params), "step": jnp.zeros((), jnp.int32)}
-
-
-def abstract_train_state(cfg: ModelConfig):
-    """ShapeDtypeStruct train state (dry-run: no allocation)."""
-    rng = jax.random.PRNGKey(0)
-    return jax.eval_shape(lambda: make_train_state(cfg, rng))
 
 
 def make_train_step(
@@ -35,8 +29,7 @@ def make_train_step(
     """``microbatches`` > 1 enables gradient accumulation: the batch splits
     along dim 0 and a scan accumulates grads, dividing peak activation
     memory (the remat carry stack) by the microbatch count at the cost of
-    smaller per-matmul shapes. Overridable via REPRO_MICROBATCH for the
-    dry-run perf sweeps."""
+    smaller per-matmul shapes. Overridable via REPRO_MICROBATCH."""
     fns = build_model(cfg)
     opt = get_optimizer(cfg.optimizer)
     sched = get_schedule(cfg.schedule, peak_lr, warmup, total_steps)
@@ -102,15 +95,6 @@ def make_train_step(
     return train_step
 
 
-def make_prefill_step(cfg: ModelConfig):
-    fns = build_model(cfg)
-
-    def prefill_step(params, batch):
-        return fns.prefill(params, batch)
-
-    return prefill_step
-
-
 def make_serve_step(cfg: ModelConfig):
     """Decode: one new token against a seq_len-sized cache (per assignment)."""
     fns = build_model(cfg)
@@ -119,8 +103,3 @@ def make_serve_step(cfg: ModelConfig):
         return fns.decode_step(params, cache, batch)
 
     return serve_step
-
-
-def abstract_cache(cfg: ModelConfig, batch: int, max_seq: int):
-    fns = build_model(cfg)
-    return jax.eval_shape(lambda: fns.init_cache(batch, max_seq))

@@ -1,8 +1,7 @@
 """Attention / MLP / MoE layers: init + apply (pure functions over pytrees).
 
 Layer params are plain dicts so they can be stacked with a leading layer axis
-and driven by ``jax.lax.scan`` (keeps HLO small — critical for the 80-cell
-CPU dry-run compiles).
+and driven by ``jax.lax.scan`` (keeps HLO small and compiles short).
 """
 from __future__ import annotations
 
@@ -194,8 +193,7 @@ def moe_apply(p, x, cfg: ModelConfig):
 
     Dispatch/combine use scatter/gather (token sort into expert slots) rather
     than dense one-hot einsums: the (tokens × experts × capacity) einsum would
-    dominate compiled FLOPs by >100× and destroy the roofline useful-compute
-    ratio (see DESIGN.md).
+    dominate compiled FLOPs by >100× and bury the useful compute.
     """
     m = cfg.moe
     b, s, d = x.shape

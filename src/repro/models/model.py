@@ -1,7 +1,7 @@
 """Model assembly: init / forward / prefill / decode_step / loss per family.
 
-All families share the same outer contract so the launcher, dry-run, MSched
-workload generators, and tests are arch-agnostic:
+All families share the same outer contract so the launchers, the live MSched
+runtime, the workload generators and the tests are arch-agnostic:
 
   init(rng)                         -> params
   forward(params, batch)            -> logits (B, S, V)       [train shapes]
@@ -11,7 +11,7 @@ workload generators, and tests are arch-agnostic:
   init_cache(batch, max_seq)        -> cache pytree
 
 Layer stacks run under ``jax.lax.scan`` (+ optional remat) so that the HLO is
-layer-count-independent: essential for compiling 80 dry-run cells on CPU.
+layer-count-independent, which keeps compiles of deep models short.
 """
 from __future__ import annotations
 
@@ -132,14 +132,7 @@ def _load_balance_loss(router_logits, cfg: ModelConfig):
 
 
 def _remat(fn):
-    """Full layer remat (save only the scan carry; recompute everything).
-
-    Note for dry-run memory numbers: the CPU backend has no native bf16, so
-    XLA hoists a whole-stack bf16->f32 convert of the saved carries out of
-    the backward loop — an f32 copy of the residual stack that would NOT
-    exist on TPU. memory_analysis() therefore overstates training temps by
-    ~2x the carry stack; see EXPERIMENTS.md §Dry-run methodology.
-    """
+    """Full layer remat (save only the scan carry; recompute everything)."""
     return jax.checkpoint(fn, prevent_cse=False)
 
 

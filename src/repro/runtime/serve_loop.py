@@ -1,10 +1,10 @@
 """Batched serving loop with MSched-style multi-model scheduling.
 
 Hosts several models on one device budget: requests queue per model, the
-scheduler round-robins (or priority-schedules) across models, and the MSched
-coordinator proactively migrates the next model's weights into the device
-pool before its batch runs — serving-side integration of the paper's
-extended context switch (the live analogue of benchmarks fig13).
+server names the model with the oldest request as the next slice's task,
+and the MSched coordinator proactively migrates that model's weights into
+the device pool before its batch runs — serving-side integration of the
+paper's extended context switch (the live analogue of benchmarks fig13).
 """
 from __future__ import annotations
 
@@ -96,7 +96,7 @@ class MultiModelServer:
             model = min(pending, key=lambda m: pending[m][0].arrival_s)
             # run one slice for that model via the MSched runtime
             before = rt.stats.steps[model]
-            rt.policy._rr = [model] + [m for m in rt.tasks if m != model]
+            rt.put_first(model)
             started = time.perf_counter()
             rt.run(total_slices=1)
             now = time.perf_counter()

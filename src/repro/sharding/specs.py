@@ -1,27 +1,24 @@
-"""Sharding rules: parameter / optimizer-state / activation / cache specs.
+"""Sharding rules: parameter and optimizer-state specs.
 
-Strategy (see DESIGN.md §5):
+Strategy:
   * TP over ``model``: attention heads, FFN hidden, vocab, SSM heads,
     RG-LRU channels; experts over ``model`` (EP) when E >= |model|, else
     per-expert tensor parallelism.
   * FSDP over ``data``: the non-TP dimension of every large matrix (ZeRO-
     style, optimizer state follows parameters).
   * DP over ``(pod, data)``: the global batch; gradients reduce over both.
-  * SP: decode-time KV caches shard the sequence dim over ``model``
-    (flash-decoding-style distributed attention combine by GSPMD).
 
 Rules are path-based over the parameter pytree, so every architecture in the
 zoo gets consistent shardings without per-model code.
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any
 
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig, ShapeSpec
+from repro.configs.base import ModelConfig
 
 
 def _path_str(path) -> str:
@@ -155,59 +152,3 @@ def opt_state_shardings(cfg: ModelConfig, opt_shape: Any, pspecs: Any, mesh) -> 
     if isinstance(opt_shape, dict) and "mu" in opt_shape:
         return {"mu": pspecs}
     return jax.tree.map(lambda _: NamedSharding(mesh, P()), opt_shape)
-
-
-def batch_shardings(cfg: ModelConfig, spec: Dict[str, jax.ShapeDtypeStruct], mesh) -> Dict:
-    """Input batch: global batch over (pod, data)."""
-    from repro.launch.mesh import dp_axes
-
-    dp = dp_axes(mesh)
-    dp = dp if np.prod([mesh.shape[a] for a in dp]) <= list(spec.values())[0].shape[
-        0 if "positions3" not in spec else 0
-    ] else ("data",)
-    out = {}
-    for name, sds in spec.items():
-        b = sds.shape[0]
-        dpa = dp if (b % int(np.prod([mesh.shape[a] for a in dp])) == 0) else None
-        if name == "positions3":  # (3, B, S)
-            out[name] = NamedSharding(mesh, P(None, dpa, None))
-        elif name == "frames":  # (B, S, D)
-            out[name] = NamedSharding(mesh, P(dpa, None, None))
-        elif name == "vision_embeds":
-            out[name] = NamedSharding(mesh, P(dpa, None, None))
-        else:  # tokens / labels / frame_mask: (B, S)
-            out[name] = NamedSharding(mesh, P(dpa, *([None] * (len(sds.shape) - 1))))
-    return out
-
-
-def cache_shardings(cfg: ModelConfig, cache_shape: Any, mesh, batch: int) -> Any:
-    """Decode caches. KV sequence dim shards over `model` (SP / flash-
-    decoding); batch over `data` when divisible."""
-    data_n = mesh.shape["data"]
-    model_n = mesh.shape["model"]
-    b_ax = "data" if batch % data_n == 0 else None
-
-    def leaf(path, l):
-        name = _path_str(path)
-        shape = l.shape
-        if name in ("k", "v"):  # (L, B, S, Hkv, hd)
-            s_ax = "model" if shape[2] % model_n == 0 else None
-            return NamedSharding(mesh, P(None, b_ax, s_ax, None, None))
-        if name == "state":  # ssm: (L, B, nh, hd, ns)
-            h_ax = "model" if shape[2] % model_n == 0 else None
-            return NamedSharding(mesh, P(None, b_ax, h_ax, None, None))
-        if name == "conv":  # ssm: (L,B,W,C) / hybrid: (n_rec,B,W,D)
-            c_ax = "model" if shape[-1] % model_n == 0 else None
-            return NamedSharding(mesh, P(None, b_ax, None, c_ax))
-        if name == "h":  # hybrid rec state (n_rec, B, D)
-            d_ax = "model" if shape[2] % model_n == 0 else None
-            return NamedSharding(mesh, P(None, b_ax, d_ax))
-        if name == "index":
-            return NamedSharding(mesh, P())
-        return NamedSharding(mesh, P(*([None] * len(shape))))
-
-    return jax.tree_util.tree_map_with_path(leaf, cache_shape)
-
-
-def replicated(mesh, tree: Any) -> Any:
-    return jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)

@@ -1,5 +1,10 @@
 """MultiModelServer: the live (real-JAX) serving loop hosting several models
 on one device budget with MSched-style proactive migration."""
+import json
+import os
+import subprocess
+import sys
+
 import pytest
 
 from repro.runtime.serve_loop import MultiModelServer, Request
@@ -58,3 +63,66 @@ def test_unanswered_request_has_only_its_submit_stamp(server):
     assert req.submitted_s is not None
     assert (req.started_s, req.answered_s, req.slice, req.step) == (None, None, None, None)
     server.queues[0].clear()
+
+
+# the coordinator's planning inputs at every switch: the rotation ``run``
+# makes on its own, and the one ``serve`` makes when it names the model
+RUN_PLANS = [
+    (0, [0, 1, 2, 0, 1, 2, 0]),
+    (1, [1, 2, 0, 1, 2, 0, 1]),
+    (2, [2, 0, 1, 2, 0, 1, 2]),
+    (0, [0, 1, 2, 0, 1, 2, 0]),
+]
+SERVE_PLANS = [
+    (2, [2, 0, 1, 2, 0, 1, 2]),
+    (0, [0, 1, 2, 0, 1, 2, 0]),
+    (1, [1, 0, 2, 1, 0, 2, 1]),
+]
+
+
+@pytest.mark.parametrize("path", ["run", "serve"])
+def test_planning_timeline_is_unchanged(path, monkeypatch):
+    server = MultiModelServer(["qwen3-1.7b", "minicpm-2b", "mamba2-1.3b"], steps_per_slice=2)
+    coordinator = server.runtime.coordinator
+    plans = []
+    plan = coordinator.on_context_switch
+
+    def record(next_task, timeline, *args, **kwargs):
+        plans.append((next_task, timeline.task_ids(), {e.timeslice_us for e in timeline}))
+        return plan(next_task, timeline, *args, **kwargs)
+
+    monkeypatch.setattr(coordinator, "on_context_switch", record)
+    if path == "run":
+        server.runtime.run(total_slices=4)
+        expected = RUN_PLANS
+    else:
+        for model, arrival in [(2, 0.0), (0, 0.1), (1, 0.2), (2, 0.3)]:
+            server.submit(Request(model=model, arrival_s=arrival))
+        server.serve(wall_budget_s=60.0)
+        expected = SERVE_PLANS
+    assert plans == [(tid, ids, {2000.0}) for tid, ids in expected]
+
+
+def test_chip_path_loads_no_simulator():
+    """Importing the server loads none of the simulator's layers."""
+    code = (
+        "import json, sys, repro.runtime.serve_loop\n"
+        "print(json.dumps(sorted(sys.modules)))"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, env=env, timeout=120
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    loaded = json.loads(out.stdout.strip().splitlines()[-1])
+    simulator = (
+        "repro.core.simulator",
+        "repro.core.demand_paging",
+        "repro.core.workloads",
+        "repro.serving",
+        "repro.cluster",
+        "repro.control",
+        "repro.telemetry",
+    )
+    assert [m for m in loaded if m.startswith(simulator)] == []
